@@ -324,9 +324,6 @@ def _fleet_runner(cfg: PSOGAConfig, traffic: bool = False,
     if tel is not None:
         tel.inc("runner_cache.lookup_hits" if hit
                 else "runner_cache.lookup_misses")
-        tel.instant("runner_cache_hit" if hit else "runner_cache_miss",
-                    bucket=str(shape_bucket), traffic=traffic,
-                    mesh=mesh is not None)
     return cached
 
 
@@ -536,8 +533,11 @@ def run_pso_ga_batch(problems: Sequence[ProblemLike],
         data-shard count with masked dummy problems whose results are
         discarded. Gene-for-gene identical to the single-device solve
         (DESIGN.md §12). ``None`` keeps today's single-device path.
-      telemetry: a ``Telemetry`` channel (DESIGN.md §13) — each bucket's
-        runner dispatch is wrapped in a ``fleet_solve`` span. ``None``
+      telemetry: a ``Telemetry`` channel (DESIGN.md §13) — the call is
+        a ``fleet_batch`` span holding ``fleet_pack``, then per bucket
+        ``swarm_init``, ``fleet_inputs``, ``fleet_solve`` (the runner
+        dispatch), ``resimulate`` (ending in ``block_until_ready``) and
+        ``unpack``, and ``gather_state`` when ``return_state``. ``None``
         falls back to the process-global channel (``set_telemetry``);
         with neither, the solve path is bit-identical to pre-telemetry
         behavior.
@@ -550,118 +550,144 @@ def run_pso_ga_batch(problems: Sequence[ProblemLike],
     traffic key when ``arrivals`` is given); ``best_cost`` is always
     the raw zero-load replayed plan cost.
     """
-    probs = _as_problems(problems)
-    n = len(probs)
     tel = telemetry if telemetry is not None else get_telemetry()
-    seeds = _normalize_seeds(seed, n)
-    if incumbent is not None and len(incumbent) != n:
-        raise ValueError(f"{len(incumbent)} incumbents for {n} problems")
-    if arrivals is not None and len(arrivals) != n:
-        raise ValueError(f"{len(arrivals)} arrival sets for {n} problems")
-    mig_arr = np.broadcast_to(
-        np.asarray(migration_weight, np.float32), (n,))
+    with maybe_span(tel, "fleet_batch", n=len(problems)) as span_args:
+        with maybe_span(tel, "fleet_pack"):
+            probs = _as_problems(problems)
+            n = len(probs)
+            seeds = _normalize_seeds(seed, n)
+            if incumbent is not None and len(incumbent) != n:
+                raise ValueError(
+                    f"{len(incumbent)} incumbents for {n} problems")
+            if arrivals is not None and len(arrivals) != n:
+                raise ValueError(
+                    f"{len(arrivals)} arrival sets for {n} problems")
+            mig_arr = np.broadcast_to(
+                np.asarray(migration_weight, np.float32), (n,))
+            fleet = pack_fleet(probs, bucket=bucket)
+        if span_args is not None:
+            span_args["buckets"] = len(fleet.buckets)
+        traffic = arrivals is not None
+        shards = 1
+        if mesh is not None:
+            from ..launch.mesh import data_shard_count
+            shards = data_shard_count(mesh)
 
-    fleet = pack_fleet(probs, bucket=bucket)
-    traffic = arrivals is not None
-    shards = 1
-    if mesh is not None:
-        from ..launch.mesh import data_shard_count
-        shards = data_shard_count(mesh)
+        results: List[Optional[PSOGAResult]] = [None] * n
+        bucket_states: List[Tuple[FleetBucket, _SwarmState]] = []
+        for b in fleet.buckets:
+            nb = int(b.idx.shape[0])
+            name = f"{b.max_p}x{b.max_S}"
+            with maybe_span(tel, "swarm_init", bucket=name, n=nb):
+                keys_a, X0b, incb, migb = _init_bucket(
+                    b, probs, seeds, cfg, incumbent, mig_arr, warm_rescue)
+            with maybe_span(tel, "fleet_inputs", bucket=name, n=nb):
+                arrb = None
+                if traffic:
+                    arrb = pack_arrivals([arrivals[i] for i in b.idx],
+                                         fleet.max_apps)
+                ppb = b.ppb
+                pad = (-nb) % shards
+                if pad:
+                    ppb = jax.tree.map(lambda a: _pad_rows(a, pad), ppb)
+                    keys_a = _pad_rows(keys_a, pad)
+                    X0b = _pad_rows(X0b, pad)
+                    incb = _pad_rows(incb, pad)
+                    migb = _pad_rows(migb, pad)
+                    if arrb is not None:
+                        arrb = _pad_rows(arrb, pad)
+                runner = _fleet_runner(cfg, traffic=traffic,
+                                       shape_bucket=(b.max_p, b.max_S),
+                                       mesh=mesh, telemetry=tel)
+                args = (ppb, jnp.asarray(keys_a), jnp.asarray(X0b),
+                        jnp.asarray(incb), jnp.asarray(migb))
+                if traffic:
+                    args = args + (jnp.asarray(arrb),)
+            with maybe_span(tel, "fleet_solve", bucket=name, n=nb,
+                            traffic=traffic, sharded=mesh is not None):
+                state = runner(*args)
+                jax.block_until_ready(state.gbest_f)
 
-    results: List[Optional[PSOGAResult]] = [None] * n
-    bucket_states: List[Tuple[FleetBucket, _SwarmState]] = []
-    for b in fleet.buckets:
-        nb = int(b.idx.shape[0])
-        # Per-problem init mirrors run_pso_ga exactly: split the
-        # problem's own key, draw the link-aware swarm at the TRUE
-        # (p, S) shape, then embed into the bucket's padded gene space
-        # (padded genes start — and stay — 0). Seeds, incumbents,
-        # rescue flags, and arrivals all route by ORIGINAL index, so
-        # bucket assignment never reshuffles a problem's inputs.
-        keys_l = []
-        X0b = np.zeros((nb, cfg.pop_size, b.max_p), np.int32)
-        incb = np.zeros((nb, b.max_p), np.int32)
-        migb = np.zeros((nb,), np.float32)
-        for j, i in enumerate(b.idx):
-            pr = probs[i]
-            key, k_init = jax.random.split(jax.random.PRNGKey(seeds[i]))
-            keys_l.append(np.asarray(key))
-            inc_i = None
-            rescue_i = False
-            if incumbent is not None and incumbent[i] is not None:
-                inc_i = np.asarray(incumbent[i], np.int32)
-                if inc_i.shape != (pr.num_layers,):
-                    raise ValueError(
-                        f"incumbent[{i}] has shape {inc_i.shape}, "
-                        f"expected ({pr.num_layers},)")
-                incb[j, :pr.num_layers] = inc_i
-                migb[j] = mig_arr[i]
-                rescue_i = bool(warm_rescue[i]) if warm_rescue is not None \
-                    else False
-            # else: a demoted problem (stale incumbent, DESIGN.md §11)
-            # solves cold inside the warm fleet: zero migration weight
-            # multiplies the term away bit-exactly, and init_swarm gets
-            # no incumbent — identical to a cold solve of problem i.
-            X0b[j, :, :pr.num_layers] = np.asarray(
-                init_swarm(k_init, pr, cfg, incumbent=inc_i,
-                           rescue=rescue_i))
-        keys_a = np.stack(keys_l)
-        arrb = None
-        if traffic:
-            arrb = pack_arrivals([arrivals[i] for i in b.idx],
-                                 fleet.max_apps)
+            # Re-simulate each gbest (same as the sequential epilogue).
+            with maybe_span(tel, "resimulate", bucket=name, n=nb):
+                if pad:
+                    state = jax.tree.map(lambda a: a[:nb], state)
+                res = jax.vmap(
+                    lambda pp, x: simulate_padded(pp, x, cfg.faithful_sim))(
+                        b.ppb, state.gbest_x)
+                if tel is not None:
+                    jax.block_until_ready(res)
+            with maybe_span(tel, "unpack", bucket=name, n=nb):
+                for j, i in enumerate(b.idx):
+                    pr = probs[i]
+                    feasible = bool(res.feasible[j])
+                    results[i] = PSOGAResult(
+                        best_x=np.asarray(state.gbest_x[j])[:pr.num_layers],
+                        best_fitness=float(state.gbest_f[j]),
+                        best_cost=float(res.total_cost[j]) if feasible
+                        else float("inf"),
+                        feasible=feasible,
+                        iterations=int(state.it[j]),
+                        history=None)
+            bucket_states.append((b, state))
 
-        ppb = b.ppb
-        pad = (-nb) % shards
-        if pad:
-            ppb = jax.tree.map(lambda a: _pad_rows(a, pad), ppb)
-            keys_a = _pad_rows(keys_a, pad)
-            X0b = _pad_rows(X0b, pad)
-            incb = _pad_rows(incb, pad)
-            migb = _pad_rows(migb, pad)
-            if arrb is not None:
-                arrb = _pad_rows(arrb, pad)
+        if not return_state:
+            return results
+        with maybe_span(tel, "gather_state"):
+            return results, _gather_state(bucket_states, n, cfg)
 
-        runner = _fleet_runner(cfg, traffic=traffic,
-                               shape_bucket=(b.max_p, b.max_S),
-                               mesh=mesh, telemetry=tel)
-        args = (ppb, jnp.asarray(keys_a), jnp.asarray(X0b),
-                jnp.asarray(incb), jnp.asarray(migb))
-        if traffic:
-            args = args + (jnp.asarray(arrb),)
-        with maybe_span(tel, "fleet_solve",
-                        bucket=f"{b.max_p}x{b.max_S}", n=nb,
-                        traffic=traffic, sharded=mesh is not None):
-            state = runner(*args)
-            jax.block_until_ready(state.gbest_f)
-        if pad:
-            state = jax.tree.map(lambda a: a[:nb], state)
 
-        # Re-simulate each gbest (same as the sequential epilogue).
-        res = jax.vmap(
-            lambda pp, x: simulate_padded(pp, x, cfg.faithful_sim))(
-                b.ppb, state.gbest_x)
-        for j, i in enumerate(b.idx):
-            pr = probs[i]
-            feasible = bool(res.feasible[j])
-            results[i] = PSOGAResult(
-                best_x=np.asarray(state.gbest_x[j])[:pr.num_layers],
-                best_fitness=float(state.gbest_f[j]),
-                best_cost=float(res.total_cost[j]) if feasible
-                else float("inf"),
-                feasible=feasible,
-                iterations=int(state.it[j]),
-                history=None)
-        bucket_states.append((b, state))
+def _init_bucket(b: FleetBucket, probs: Sequence[SimProblem],
+                 seeds: Sequence[int], cfg: PSOGAConfig,
+                 incumbent: Optional[Sequence[np.ndarray]],
+                 mig_arr: np.ndarray,
+                 warm_rescue: Optional[Sequence[bool]]
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """One bucket's solver inputs ``(keys, X0b, incb, migb)``.
 
-    if not return_state:
-        return results
+    Per-problem init mirrors run_pso_ga exactly: split the problem's own
+    key, draw the link-aware swarm at the TRUE (p, S) shape, then embed
+    into the bucket's padded gene space (padded genes start — and stay —
+    0). Seeds, incumbents and rescue flags all route by ORIGINAL index,
+    so bucket assignment never reshuffles a problem's inputs."""
+    nb = int(b.idx.shape[0])
+    keys_l = []
+    X0b = np.zeros((nb, cfg.pop_size, b.max_p), np.int32)
+    incb = np.zeros((nb, b.max_p), np.int32)
+    migb = np.zeros((nb,), np.float32)
+    for j, i in enumerate(b.idx):
+        pr = probs[i]
+        key, k_init = jax.random.split(jax.random.PRNGKey(seeds[i]))
+        keys_l.append(np.asarray(key))
+        inc_i = None
+        rescue_i = False
+        if incumbent is not None and incumbent[i] is not None:
+            inc_i = np.asarray(incumbent[i], np.int32)
+            if inc_i.shape != (pr.num_layers,):
+                raise ValueError(
+                    f"incumbent[{i}] has shape {inc_i.shape}, "
+                    f"expected ({pr.num_layers},)")
+            incb[j, :pr.num_layers] = inc_i
+            migb[j] = mig_arr[i]
+            rescue_i = bool(warm_rescue[i]) if warm_rescue is not None \
+                else False
+        # else: a demoted problem (stale incumbent, DESIGN.md §11)
+        # solves cold inside the warm fleet: zero migration weight
+        # multiplies the term away bit-exactly, and init_swarm gets
+        # no incumbent — identical to a cold solve of problem i.
+        X0b[j, :, :pr.num_layers] = np.asarray(
+            init_swarm(k_init, pr, cfg, incumbent=inc_i,
+                       rescue=rescue_i))
+    return np.stack(keys_l), X0b, incb, migb
 
-    # Re-assemble one fleet-ordered state across buckets at the largest
-    # bucket's max_p: genes beyond a problem's own bucket shape are 0 —
-    # the same "padded genes untouched" invariant the single-bucket
-    # state had (tests/test_batch.py::test_padding_never_selected).
-    gmax_p = max(b.max_p for b in fleet.buckets)
+
+def _gather_state(bucket_states: Sequence[Tuple[FleetBucket, _SwarmState]],
+                  n: int, cfg: PSOGAConfig) -> _SwarmState:
+    """Re-assemble one fleet-ordered state across buckets at the largest
+    bucket's max_p: genes beyond a problem's own bucket shape are 0 —
+    the same "padded genes untouched" invariant the single-bucket state
+    had (tests/test_batch.py::test_padding_never_selected)."""
+    gmax_p = max(b.max_p for b, _ in bucket_states)
     st0 = bucket_states[0][1]
     key_g = np.zeros((n,) + st0.key.shape[1:], np.asarray(st0.key).dtype)
     X_g = np.zeros((n, cfg.pop_size, gmax_p), np.int32)
@@ -680,9 +706,8 @@ def run_pso_ga_batch(problems: Sequence[ProblemLike],
         gbf_g[b.idx] = np.asarray(st.gbest_f)
         it_g[b.idx] = np.asarray(st.it)
         stall_g[b.idx] = np.asarray(st.stall)
-    state_out = _SwarmState(
+    return _SwarmState(
         key=jnp.asarray(key_g), X=jnp.asarray(X_g),
         pbest_x=jnp.asarray(pbx_g), pbest_f=jnp.asarray(pbf_g),
         gbest_x=jnp.asarray(gbx_g), gbest_f=jnp.asarray(gbf_g),
         it=jnp.asarray(it_g), stall=jnp.asarray(stall_g))
-    return results, state_out

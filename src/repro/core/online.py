@@ -506,12 +506,13 @@ def _replan_round_body(probs, incumbent, cfg, seed, round_no, label,
     # rescuing garbage.
     checked: List[Optional[np.ndarray]] = []
     demoted = np.zeros(n, bool)
-    for i, (pr, inc) in enumerate(zip(probs, incumbent)):
-        if inc is not None and plan_is_valid(pr, inc):
-            checked.append(np.asarray(inc, np.int32))
-        else:
-            demoted[i] = True
-            checked.append(None)
+    with maybe_span(telemetry, "plan_check", round=round_no):
+        for i, (pr, inc) in enumerate(zip(probs, incumbent)):
+            if inc is not None and plan_is_valid(pr, inc):
+                checked.append(np.asarray(inc, np.int32))
+            else:
+                demoted[i] = True
+                checked.append(None)
     with maybe_span(telemetry, "incumbent_keys", round=round_no):
         inc_key = incumbent_keys(probs, checked, cfg.pso,
                                  arrivals=arrivals)
@@ -581,7 +582,6 @@ def _replan_round_body(probs, incumbent, cfg, seed, round_no, label,
         telemetry.inc("online.rounds")
         telemetry.inc("online.replanned", int(replanned.sum()))
         telemetry.inc("online.demotions", int(demoted.sum()))
-        telemetry.observe("online.round_wall_s", wall)
     return plans, log
 
 

@@ -423,97 +423,98 @@ def run_service(dags: Sequence[LayerDAG], trace: EnvTrace,
         if tel is not None and n:
             tel.inc(f"service.{name}", n)
 
-    rcfg = cfg.replan
-    burst_rcfg = dataclasses.replace(rcfg, pso=cfg.burst)
-    cache = plan_cache
-    if cache is None and cfg.plan_cache is not None:
-        cache = PlanCache(cfg.plan_cache, telemetry=tel)
-    fps = [dag_fingerprint(d) for d in dags] if cache is not None else None
-    injector = None
-    if cfg.chaos is not None and (cfg.chaos.crash_rounds
-                                  or cfg.chaos.p_crash > 0.0):
-        injector = FailureInjector(p_fail=cfg.chaos.p_crash,
-                                   seed=cfg.chaos.seed,
-                                   fail_at=tuple(cfg.chaos.crash_rounds),
-                                   max_failures=cfg.chaos.max_crashes)
-    breaker = CircuitBreaker(threshold=cfg.breaker_threshold,
-                             cooldown=cfg.breaker_cooldown)
-    detector = StragglerDetector(warmup=cfg.straggler_warmup)
-    per_iter = EwmaEstimator()
-    windows: Optional[List[_RateWindow]] = None
-    if cfg.estimate_rates and rcfg.traffic is not None:
-        windows = [_RateWindow(cfg.window_rounds, rcfg.traffic.horizon,
-                               d.num_apps) for d in dags]
+    with maybe_span(tel, "service_setup"):
+        rcfg = cfg.replan
+        burst_rcfg = dataclasses.replace(rcfg, pso=cfg.burst)
+        cache = plan_cache
+        if cache is None and cfg.plan_cache is not None:
+            cache = PlanCache(cfg.plan_cache, telemetry=tel)
+        fps = [dag_fingerprint(d) for d in dags] if cache is not None else None
+        injector = None
+        if cfg.chaos is not None and (cfg.chaos.crash_rounds
+                                      or cfg.chaos.p_crash > 0.0):
+            injector = FailureInjector(p_fail=cfg.chaos.p_crash,
+                                       seed=cfg.chaos.seed,
+                                       fail_at=tuple(cfg.chaos.crash_rounds),
+                                       max_failures=cfg.chaos.max_crashes)
+        breaker = CircuitBreaker(threshold=cfg.breaker_threshold,
+                                 cooldown=cfg.breaker_cooldown)
+        detector = StragglerDetector(warmup=cfg.straggler_warmup)
+        per_iter = EwmaEstimator()
+        windows: Optional[List[_RateWindow]] = None
+        if cfg.estimate_rates and rcfg.traffic is not None:
+            windows = [_RateWindow(cfg.window_rounds, rcfg.traffic.horizon,
+                                   d.num_apps) for d in dags]
 
-    def _observe(k: int, i: int) -> Tuple[int, int, np.ndarray]:
-        """One (round, dag, timestamps) arrival observation — the exact
-        draw the synchronous estimate_rates path makes in-loop, so the
-        deterministic ingestion mode is bit-identical to it."""
-        tc = rcfg.traffic
-        obs = tc.solver_arrivals(
-            dags[i].num_apps, seed=seed + 7919 * k + 31 * i,
-            rate_scale=trace.events[k].load_scale)[0]
-        return (k, i, obs)
+        def _observe(k: int, i: int) -> Tuple[int, int, np.ndarray]:
+            """One (round, dag, timestamps) arrival observation — the exact
+            draw the synchronous estimate_rates path makes in-loop, so the
+            deterministic ingestion mode is bit-identical to it."""
+            tc = rcfg.traffic
+            obs = tc.solver_arrivals(
+                dags[i].num_apps, seed=seed + 7919 * k + 31 * i,
+                rate_scale=trace.events[k].load_scale)[0]
+            return (k, i, obs)
 
-    # async ingestion (phase 2): observations ride a bounded queue. With
-    # threads=0 the round loop enqueues its own round synchronously —
-    # deterministic and bit-identical to the legacy path; with threads>0
-    # producers pre-draw future rounds' observations concurrently.
-    queue: Optional[ArrivalQueue] = None
-    producers: List[threading.Thread] = []
-    stop = threading.Event()
-    if cfg.ingest is not None:
-        if windows is None:
-            raise ValueError("ingest requires a traffic model "
-                             "(cfg.replan.traffic) to observe")
-        queue = ArrivalQueue(cfg.ingest.capacity, telemetry=tel)
+        # async ingestion (phase 2): observations ride a bounded queue. With
+        # threads=0 the round loop enqueues its own round synchronously —
+        # deterministic and bit-identical to the legacy path; with threads>0
+        # producers pre-draw future rounds' observations concurrently.
+        queue: Optional[ArrivalQueue] = None
+        producers: List[threading.Thread] = []
+        stop = threading.Event()
+        if cfg.ingest is not None:
+            if windows is None:
+                raise ValueError("ingest requires a traffic model "
+                                 "(cfg.replan.traffic) to observe")
+            queue = ArrivalQueue(cfg.ingest.capacity, telemetry=tel)
 
-        def _produce(idxs: List[int]) -> None:
-            for kk in range(1, trace.num_rounds):
-                for ii in idxs:
-                    if stop.is_set():
-                        return
-                    queue.put(_observe(kk, ii))
+            def _produce(idxs: List[int]) -> None:
+                for kk in range(1, trace.num_rounds):
+                    for ii in idxs:
+                        if stop.is_set():
+                            return
+                        queue.put(_observe(kk, ii))
 
-        n_threads = min(int(cfg.ingest.threads), len(dags))
-        for t in range(n_threads):
-            th = threading.Thread(
-                target=_produce, args=(list(range(t, len(dags),
-                                                  n_threads)),),
-                daemon=True)
-            producers.append(th)
-            th.start()
+            n_threads = min(int(cfg.ingest.threads), len(dags))
+            for t in range(n_threads):
+                th = threading.Thread(
+                    target=_produce, args=(list(range(t, len(dags),
+                                                      n_threads)),),
+                    daemon=True)
+                producers.append(th)
+                th.start()
 
-    # the counters schema is STABLE: every key is present from round 0
-    # (ingest_* stay 0 without async ingestion) so downstream consumers
-    # never need existence checks.
-    counters = {"retries": 0, "crashes": 0, "stale_env_rounds": 0,
-                "stalls_flagged": 0, "breaker_opened": 0,
-                "watchdog_cuts": 0, "rejected_apps": 0, "demotions": 0,
-                "ingest_enqueued": 0, "ingest_dropped": 0,
-                "ingest_drained": 0, "ingest_leftover": 0}
-    fallback_counts = {r: 0 for r in LADDER_RUNGS}
+        # the counters schema is STABLE: every key is present from round 0
+        # (ingest_* stay 0 without async ingestion) so downstream consumers
+        # never need existence checks.
+        counters = {"retries": 0, "crashes": 0, "stale_env_rounds": 0,
+                    "stalls_flagged": 0, "breaker_opened": 0,
+                    "watchdog_cuts": 0, "rejected_apps": 0, "demotions": 0,
+                    "ingest_enqueued": 0, "ingest_dropped": 0,
+                    "ingest_drained": 0, "ingest_leftover": 0}
+        fallback_counts = {r: 0 for r in LADDER_RUNGS}
 
-    # round 0: the cold solve, exactly replan_fleet's (or admission-time
-    # plans handed in, e.g. from plan_offload_batch).
-    env0 = trace.env_at(0)
-    if initial is None:
-        probs0 = [SimProblem.build(d, env0) for d in dags]
-        with maybe_span(tel, "cold_solve", n=len(dags)):
-            cold = run_pso_ga_batch(
-                probs0, rcfg.pso, seed=seed,
-                arrivals=_round_arrivals(rcfg, dags, trace.events[0],
-                                         seed),
-                mesh=rcfg.mesh, telemetry=tel)
-    else:
-        if len(initial) != len(dags):
-            raise ValueError(f"{len(initial)} initial results for "
-                             f"{len(dags)} dags")
-        cold = list(initial)
-    plans: List[Optional[np.ndarray]] = [
-        np.asarray(r.best_x, np.int32) for r in cold]
-    last_good_env = env0
-    rounds: List[ServiceRoundLog] = []
+        # round 0: the cold solve, exactly replan_fleet's (or admission-time
+        # plans handed in, e.g. from plan_offload_batch).
+        env0 = trace.env_at(0)
+        if initial is None:
+            probs0 = [SimProblem.build(d, env0) for d in dags]
+            with maybe_span(tel, "cold_solve", n=len(dags)):
+                cold = run_pso_ga_batch(
+                    probs0, rcfg.pso, seed=seed,
+                    arrivals=_round_arrivals(rcfg, dags, trace.events[0],
+                                             seed),
+                    mesh=rcfg.mesh, telemetry=tel)
+        else:
+            if len(initial) != len(dags):
+                raise ValueError(f"{len(initial)} initial results for "
+                                 f"{len(dags)} dags")
+            cold = list(initial)
+        plans: List[Optional[np.ndarray]] = [
+            np.asarray(r.best_x, np.int32) for r in cold]
+        last_good_env = env0
+        rounds: List[ServiceRoundLog] = []
 
     for k in range(1, trace.num_rounds):
       ev = trace.events[k]
@@ -529,7 +530,8 @@ def run_service(dags: Sequence[LayerDAG], trace: EnvTrace,
             env_k = last_good_env
         else:
             last_good_env = env_k
-        probs = [SimProblem.build(d, env_k) for d in dags]
+        with maybe_span(tel, "build_problems", round=k):
+            probs = [SimProblem.build(d, env_k) for d in dags]
 
         # rate estimation: ingest this round's observations — via the
         # bounded queue when async ingestion is on, else the legacy
@@ -579,22 +581,23 @@ def run_service(dags: Sequence[LayerDAG], trace: EnvTrace,
           if tel is not None:
             tel.instant("cache_hit" if cache_hit else "cache_miss",
                         round=k)
-            tel.observe("service.cache_lookup_s", cache_wall)
 
         rejected = 0
         arrivals = None
         if not cache_hit:
-            if windows is not None:
-                tc = rcfg.traffic
-                arrivals = [tc.solver_arrivals(
-                    dags[i].num_apps, seed=seed + 1000 * k + 31 * i,
-                    rate_scale=max(est_rates[i] / tc.rate, 1e-6))
-                    for i in range(len(dags))]
-            else:
-                arrivals = _round_arrivals(rcfg, dags, ev,
-                                           seed + 1000 * k)
-            arrivals, rejected = _triage(dags, probs, env_k,
-                                         cfg.triage_margin, arrivals)
+            with maybe_span(tel, "draw_arrivals", round=k):
+                if windows is not None:
+                    tc = rcfg.traffic
+                    arrivals = [tc.solver_arrivals(
+                        dags[i].num_apps, seed=seed + 1000 * k + 31 * i,
+                        rate_scale=max(est_rates[i] / tc.rate, 1e-6))
+                        for i in range(len(dags))]
+                else:
+                    arrivals = _round_arrivals(rcfg, dags, ev,
+                                               seed + 1000 * k)
+            with maybe_span(tel, "triage", round=k):
+                arrivals, rejected = _triage(dags, probs, env_k,
+                                             cfg.triage_margin, arrivals)
         _bump("rejected_apps", rejected)
         if tel is not None and rejected:
             tel.instant("triage_reject", round=k, apps=rejected)
@@ -687,7 +690,8 @@ def run_service(dags: Sequence[LayerDAG], trace: EnvTrace,
         probs_post, env_post = probs, env_k
         if cfg.chaos is not None and k in cfg.chaos.mid_round_down:
             env_post = _down_env(env_k, cfg.chaos.mid_round_down[k])
-            probs_post = [SimProblem.build(d, env_post) for d in dags]
+            with maybe_span(tel, "build_problems", round=k):
+                probs_post = [SimProblem.build(d, env_post) for d in dags]
             if tel is not None:
                 tel.instant("mid_round_down", round=k,
                             server=cfg.chaos.mid_round_down[k])

@@ -20,6 +20,12 @@ module is the ONE pipeline from event to export:
     B/E pairs on per-service tracks (``set_track``), point events are
     instants; nesting follows the with-statement, so a round span
     contains its cache-lookup, solve, and ladder children.
+  * **Compile spans** — JAX's own compile-stage events (trace, lower,
+    backend compile) become ``jit_trace`` / ``jit_lower`` /
+    ``jit_compile`` spans on the telemetry of the span open on the
+    compiling thread, so a recompile shows under the stage that caused
+    it. The listener is registered once, when the first ``Telemetry``
+    is built.
   * **Telemetry** — the facade bundling one registry + one tracer on a
     shared clock; every producer in the stack takes an optional
     ``telemetry`` argument defaulting to ``None``. With it unset,
@@ -367,6 +373,10 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._t0 = clock()
+        # origin of wall-clock spans (JAX's compile events) on this
+        # tracer's timeline: the clock itself when it is perf_counter
+        self._perf0 = self._t0 if clock is time.perf_counter \
+            else time.perf_counter()
 
     def _ts(self) -> float:
         return (self.clock() - self._t0) * 1e6
@@ -392,19 +402,51 @@ class SpanTracer:
 
     @contextlib.contextmanager
     def span(self, name: str, tid: Optional[int] = None,
-             **args: Any) -> Iterator[None]:
+             **args: Any) -> Iterator[Dict[str, Any]]:
         """``with tracer.span("replan_round", round=k): ...`` — a B/E
         duration pair on the current (or given) track; nested spans
-        nest on the timeline exactly like the with-statements do."""
+        nest on the timeline exactly like the with-statements do.
+
+        The span is also a ``jax.profiler.TraceAnnotation`` of the same
+        name, so a profiler trace of the process carries it beside the
+        device operations, on the profiler's own clock. Yields the
+        span's args dict: an arg known only inside the span may be
+        added to it."""
+        from jax.profiler import TraceAnnotation
         t = self._tid(tid)
+        span_args = {k: _arg(v) for k, v in args.items()}
         self._emit({"ph": "B", "ts": self._ts(), "pid": self.pid,
-                    "tid": t, "name": name,
-                    "args": {k: _arg(v) for k, v in args.items()}})
+                    "tid": t, "name": name, "args": span_args})
         try:
-            yield
+            with TraceAnnotation(name):
+                yield span_args
         finally:
             self._emit({"ph": "E", "ts": self._ts(), "pid": self.pid,
                         "tid": t, "name": name})
+
+    def span_at(self, name: str, start: float, end: float,
+                **args: Any) -> None:
+        """A span that already happened, from ``time.time()`` readings
+        (JAX's compile events): its B/E pair is appended together on
+        the current track, so it pairs like any other span. The start
+        is clamped to the tracer's creation, so no ``ts`` is negative.
+
+        The span is placed through ``perf_counter``, which is exact on
+        the default clock. Under an injected clock (a ``FakeClock`` in
+        tests) its ``ts`` counts real seconds since the tracer was
+        built, and is not comparable with the other spans' ``ts``.
+        """
+        to_perf = time.perf_counter() - time.time()
+        ts0 = max(0.0, (start + to_perf - self._perf0) * 1e6)
+        ts1 = max(ts0, (end + to_perf - self._perf0) * 1e6)
+        t = self._tid(None)
+        with self._lock:
+            self._events.append(
+                {"ph": "B", "ts": ts0, "pid": self.pid, "tid": t,
+                 "name": name,
+                 "args": {k: _arg(v) for k, v in args.items()}})
+            self._events.append({"ph": "E", "ts": ts1, "pid": self.pid,
+                                 "tid": t, "name": name})
 
     def instant(self, name: str, tid: Optional[int] = None,
                 **args: Any) -> None:
@@ -450,10 +492,21 @@ class Telemetry:
         self.clock = clock if clock is not None else time.perf_counter
         self.registry = MetricsRegistry(clock=self.clock)
         self.tracer = SpanTracer(clock=self.clock, pid=pid)
+        _listen_to_jax()
 
     # tracer delegates
-    def span(self, name: str, tid: Optional[int] = None, **args: Any):
-        return self.tracer.span(name, tid=tid, **args)
+    @contextlib.contextmanager
+    def span(self, name: str, tid: Optional[int] = None,
+             **args: Any) -> Iterator[Dict[str, Any]]:
+        """A tracer span that also makes this telemetry the one JAX's
+        compile events on this thread land in while it is open."""
+        stack = _open_stack()
+        stack.append(self)
+        try:
+            with self.tracer.span(name, tid=tid, **args) as span_args:
+                yield span_args
+        finally:
+            stack.pop()
 
     def instant(self, name: str, tid: Optional[int] = None,
                 **args: Any) -> None:
@@ -486,7 +539,8 @@ class Telemetry:
 def maybe_span(tel: Optional[Telemetry], name: str, **args: Any):
     """``with maybe_span(tel, "solve", rung=r):`` — a real span when
     telemetry is on, a free ``nullcontext`` when it is off (the
-    off-path stays untouched)."""
+    off-path stays untouched). ``as`` binds the span's args dict when
+    on, ``None`` when off."""
     if tel is None:
         return contextlib.nullcontext()
     return tel.span(name, **args)
@@ -524,3 +578,80 @@ def telemetry_scope(tel: Optional[Telemetry]) -> Iterator[None]:
         yield
     finally:
         set_telemetry(prev)
+
+
+# ---------------------------------------------------------------------------
+# JAX compile events -> spans (registered once, by the first Telemetry)
+# ---------------------------------------------------------------------------
+
+#: JAX's compile-stage time-span events and the span each becomes
+COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower",
+    "/jax/core/compile/backend_compile_duration": "jit_compile",
+}
+#: JAX's event for a compile served from the persistent cache
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: shortest jaxpr trace kept as a span, to keep an export readable. An
+#: eager ``jax.vmap`` re-traces every jitted ``jnp`` helper it meets
+#: (``_where``, ``_reduce_sum``, ...) on each call: about 900 traces of
+#: about 12 us in a 128-problem fleet call, which as spans would
+#: outnumber all other spans 40 to 1 for 0.1% of the call's time (so
+#: the compile time the spans add up to is short by that much).
+#: Lowering and backend compiles are always kept.
+MIN_TRACE_SPAN_S = 1e-3
+
+_OPEN = threading.local()
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
+
+
+def _open_stack() -> List[Telemetry]:
+    """The telemetries with a span open on this thread, innermost last."""
+    stack = getattr(_OPEN, "stack", None)
+    if stack is None:
+        stack = _OPEN.stack = []
+    return stack
+
+
+def _current() -> Optional[Telemetry]:
+    """Where an event on this thread lands: the telemetry of the
+    innermost open span, else the global channel, else nowhere."""
+    stack = getattr(_OPEN, "stack", None)
+    return stack[-1] if stack else get_telemetry()
+
+
+def _on_time_span(event: str, start_time: float, end_time: float,
+                  **kwargs: Any) -> None:
+    name = COMPILE_SPANS.get(event)
+    if name is None or (name == "jit_trace"
+                        and end_time - start_time < MIN_TRACE_SPAN_S):
+        return
+    tel = _current()
+    if tel is None:
+        return
+    tel.tracer.span_at(name, start_time, end_time,
+                       fun=kwargs.get("fun_name", ""))
+    if name == "jit_compile":
+        tel.inc("jit.compiles")
+
+
+def _on_event(event: str, **_: Any) -> None:
+    if event == CACHE_HIT_EVENT:
+        tel = _current()
+        if tel is not None:
+            tel.inc("jit.persistent_cache_hits")
+
+
+def _listen_to_jax() -> None:
+    """Register the process-wide compile listeners, once. Called when a
+    ``Telemetry`` is built, never on import: a process with telemetry
+    off has no listener at all."""
+    global _LISTENING
+    with _LISTEN_LOCK:
+        if _LISTENING:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_time_span_listener(_on_time_span)
+        jax.monitoring.register_event_listener(_on_event)
+        _LISTENING = True

@@ -254,6 +254,7 @@ def schedule_replay_folded(
         out_shape=[jax.ShapeDtypeStruct((1, p_pad), jnp.float32)] * 3,
         scratch_shapes=[pltpu.VMEM((max_p, tile_p), jnp.float32)],  # end
         interpret=interpret,
+        name="schedule_replay",
     )(*(a.reshape(1, -1) for a in (
         i32(order), f32(compute), i32(parent_idx), f32(parent_mb),
         i32(child_idx), f32(child_mb), f32(deadline))),

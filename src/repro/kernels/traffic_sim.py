@@ -279,6 +279,7 @@ def traffic_replay_folded(
         + [jax.ShapeDtypeStruct((max_apps * R, p_pad), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((R * max_p8, tile_p), jnp.float32)],
         interpret=interpret,
+        name="traffic_replay",
     )(*(a.reshape(1, -1) for a in (q_m, slot_m, slot0_m, arr_m, nv, pstep,
                                     f32(deadline), rv, arr2)),
       pad_lanes(srv.T, p_pad), lanes(exe), lanes(out_t), trans, app_col,

@@ -443,3 +443,210 @@ def test_global_channel_reaches_deep_layers(tiny_fleet):
     assert lookups > 0
     assert "service.round_wall_s" in snap["histograms"]
     assert set_telemetry(None) is None            # scope restored
+
+
+# ---------------------------------------------------------------------------
+# stage spans inside the fleet solver, compile spans, the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _span_tree(events):
+    """(name, args, children) roots of each track's B/E stream, with
+    JAX's compile spans left out."""
+    roots, stack = [], []
+    for e in events:
+        if e["ph"] == "B":
+            node = (e["name"], e.get("args", {}), [])
+            (stack[-1][2] if stack else roots).append(node)
+            stack.append(node)
+        elif e["ph"] == "E":
+            assert stack and stack.pop()[0] == e["name"]
+    assert not stack
+
+    def strip(nodes):
+        return [(n, a, strip(c)) for n, a, c in nodes
+                if not n.startswith("jit_")]
+    return strip(roots)
+
+
+@pytest.fixture(scope="module")
+def two_bucket_fleet(tiny_fleet):
+    """Two 4-layer DAGs (bucket 8) and one 12-layer chain (bucket 16)."""
+    env, dags = tiny_fleet
+    p = 12
+    chain = LayerDAG(
+        compute=np.linspace(1.0, 2.0, p) * env.power[0],
+        edges=np.array([[i, i + 1] for i in range(p - 1)]),
+        edge_mb=np.ones(p - 1), app_id=np.zeros(p, np.int32),
+        deadline=np.array([9.0]),
+        pinned=np.array([2] + [-1] * (p - 1), np.int32))
+    return [(dags[0], env), (chain, env), (dags[1], env)]
+
+
+def test_fleet_batch_stage_spans_nest(two_bucket_fleet):
+    """One ``fleet_batch`` holds ``fleet_pack``, then per bucket the
+    five stages in order, then ``gather_state``: a span per stage and
+    bucket, never per problem."""
+    from repro.core import run_pso_ga_batch
+    tel = Telemetry()
+    run_pso_ga_batch(two_bucket_fleet, FAST, seed=[1, 2, 3],
+                     return_state=True, telemetry=tel)
+    (name, args, children), = _span_tree(tel.tracer.events())
+    assert name == "fleet_batch"
+    assert args == {"n": 3, "buckets": 2}
+    stages = ["swarm_init", "fleet_inputs", "fleet_solve", "resimulate",
+              "unpack"]
+    assert [c[0] for c in children] == \
+        ["fleet_pack"] + stages * 2 + ["gather_state"]
+    per_bucket = [(c[1]["bucket"], c[1]["n"]) for c in children[1:-1]]
+    assert per_bucket == [("8x8", 2)] * 5 + [("16x8", 1)] * 5
+    assert all(not c[2] for c in children)
+
+
+def test_fleet_batch_telemetry_off_parity(two_bucket_fleet):
+    """Stage spans observe: results and the returned swarm state are
+    bit-identical with telemetry on and off."""
+    from repro.core import run_pso_ga_batch
+    off, st_off = run_pso_ga_batch(two_bucket_fleet, FAST, seed=[4, 5, 6],
+                                   return_state=True)
+    on, st_on = run_pso_ga_batch(two_bucket_fleet, FAST, seed=[4, 5, 6],
+                                 return_state=True, telemetry=Telemetry())
+    for a, b in zip(off, on):
+        assert np.array_equal(a.best_x, b.best_x)
+        assert (a.best_fitness, a.best_cost, a.feasible, a.iterations) \
+            == (b.best_fitness, b.best_cost, b.feasible, b.iterations)
+    for a, b in zip(st_off, st_on):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fresh_jit_becomes_compile_span():
+    """JAX's own compile events land as ``jit_*`` spans nested in the
+    span open on the compiling thread, and count ``jit.compiles``."""
+    import jax
+    import jax.numpy as jnp
+    tel = Telemetry()
+    fresh = jax.jit(lambda x: jnp.sin(x) * 3.25 + 0.125)
+    x = jnp.arange(7.0).block_until_ready()
+    with tel.span("stage"):
+        fresh(x).block_until_ready()
+    evs = tel.tracer.events()
+    assert [evs[0]["ph"], evs[0]["name"]] == ["B", "stage"]
+    assert [evs[-1]["ph"], evs[-1]["name"]] == ["E", "stage"]
+    compiles = [(b, e) for b, e in zip(evs, evs[1:])
+                if b["ph"] == "B" and b["name"] == "jit_compile"]
+    assert compiles, [e["name"] for e in evs]
+    b, e = next((b, e) for b, e in compiles
+                if "<lambda>" in b["args"]["fun"])
+    assert e["ph"] == "E" and e["name"] == "jit_compile"
+    assert 0.0 <= b["ts"] <= e["ts"] <= evs[-1]["ts"]
+    assert tel.registry.counter("jit.compiles").value == len(compiles)
+    # nothing open on this thread and no global channel: dropped
+    jax.jit(lambda x: x - 0.375)(jnp.ones(3)).block_until_ready()
+    assert len(tel.tracer.events()) == len(evs)
+
+
+def test_compile_spans_use_the_global_channel_outside_spans():
+    import jax
+    import jax.numpy as jnp
+    tel = Telemetry()
+    with telemetry_scope(tel):
+        jax.jit(lambda x: x * 0.625)(jnp.ones(5)).block_until_ready()
+    names = [e["name"] for e in tel.tracer.events() if e["ph"] == "B"]
+    assert "jit_compile" in names
+
+
+def test_compile_spans_clamp_to_the_tracer_start():
+    tr = SpanTracer(clock=FakeClock())
+    tr.span_at("jit_compile", time.time() - 100.0, time.time() - 99.0,
+               fun="old")
+    b, e = tr.events()
+    assert b["ts"] == e["ts"] == 0.0
+
+
+def test_short_traces_are_no_spans_short_lowerings_are():
+    """A jaxpr trace under ``MIN_TRACE_SPAN_S`` is dropped; a lowering or
+    a backend compile of any length becomes a span."""
+    from repro.core import telemetry as tm
+    tel = Telemetry()
+    now = time.time()
+    short = 0.5 * tm.MIN_TRACE_SPAN_S
+    with tel.span("stage"):
+        for event, dur in (("jaxpr_trace_duration", short),
+                           ("jaxpr_trace_duration", 2 * tm.MIN_TRACE_SPAN_S),
+                           ("jaxpr_to_mlir_module_duration", short),
+                           ("backend_compile_duration", short)):
+            tm._on_time_span(f"/jax/core/compile/{event}", now - dur, now,
+                             fun_name=event)
+    kept = [(e["name"], e["args"]["fun"]) for e in tel.tracer.events()
+            if e["ph"] == "B" and e["name"].startswith("jit_")
+            and e["args"]["fun"].endswith("_duration")]
+    assert kept == [("jit_trace", "jaxpr_trace_duration"),
+                    ("jit_lower", "jaxpr_to_mlir_module_duration"),
+                    ("jit_compile", "backend_compile_duration")]
+
+
+def test_no_listener_before_the_first_telemetry():
+    """Importing the planner and solving with telemetry off registers
+    no JAX listener; the first ``Telemetry`` registers exactly one."""
+    import subprocess
+    import sys
+    code = """
+import numpy as np
+from jax._src import monitoring
+from repro.core import (PSOGAConfig, Telemetry, run_pso_ga_batch,
+                        sample_environment, telemetry)
+from repro.core.dag import LayerDAG
+env = sample_environment()
+dag = LayerDAG(compute=np.ones(3) * env.power[0],
+               edges=np.array([[0, 1], [1, 2]]), edge_mb=np.ones(2),
+               app_id=np.zeros(3, np.int32), deadline=np.array([9.0]),
+               pinned=np.array([0, -1, -1], np.int32))
+run_pso_ga_batch([(dag, env)], PSOGAConfig(pop_size=4, max_iters=2))
+spans = monitoring.get_event_time_span_listeners
+assert telemetry._on_time_span not in spans(), spans()
+Telemetry()
+Telemetry()
+assert spans().count(telemetry._on_time_span) == 1, spans()
+assert monitoring.get_event_listeners().count(telemetry._on_event) == 1
+"""
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """Under ``jax.profiler.trace`` each span is also a host annotation,
+    on the profiler's clock beside the device operations."""
+    import glob
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    tel = Telemetry()
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("outer_stage"):
+            with tel.span("inner_stage"):
+                jnp.ones(4).sum().block_until_ready()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"outer_stage", "inner_stage"} <= names
+
+
+def test_check_trace_accepts_compile_spans(two_bucket_fleet, tmp_path):
+    """An export holding compile spans (B/E pairs appended after the
+    fact) still validates against the CI gate."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import run_pso_ga_batch
+    tel = Telemetry()
+    with tel.span("stage"):
+        jax.jit(lambda x: x * 1.875)(jnp.ones(2)).block_until_ready()
+    run_pso_ga_batch(two_bucket_fleet, FAST, seed=7, telemetry=tel)
+    path = str(tmp_path / "trace.json")
+    tel.export_trace(path)
+    n = _check_trace_module().check_trace(
+        path, require=["jit_compile", "fleet_batch", "swarm_init",
+                       "resimulate"])
+    assert n > 0
