@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+from functools import partial
 from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
                     Tuple, Union)
 
@@ -57,8 +58,8 @@ import numpy as np
 from .dag import LayerDAG
 from .environment import Environment
 from .fitness import make_swarm_fitness, resolve_fitness_backend
-from .pso_ga import (PSOGAConfig, PSOGAResult, _SwarmState, init_swarm,
-                     swarm_step)
+from .pso_ga import (PSOGAConfig, PSOGAResult, _SwarmState, _init_arrays,
+                     _init_core, swarm_step)
 from .seeding import coerce_seed
 from .simulator import PaddedProblem, SimProblem, pad_problem, simulate_padded
 from .telemetry import get_telemetry, maybe_span
@@ -535,8 +536,11 @@ def run_pso_ga_batch(problems: Sequence[ProblemLike],
         (DESIGN.md §12). ``None`` keeps today's single-device path.
       telemetry: a ``Telemetry`` channel (DESIGN.md §13) — the call is
         a ``fleet_batch`` span holding ``fleet_pack``, then per bucket
-        ``swarm_init``, ``fleet_inputs``, ``fleet_solve`` (the runner
-        dispatch), ``resimulate`` (ending in ``block_until_ready``) and
+        ``swarm_init`` (arg ``groups``, also summed by the
+        ``swarm_init.groups`` counter: the init programs it ran, one
+        per exact ``(p, S)``), ``fleet_inputs``, ``fleet_solve`` (the
+        runner dispatch), ``resimulate`` (ending in
+        ``block_until_ready``) and
         ``unpack``, and ``gather_state`` when ``return_state``. ``None``
         falls back to the process-global channel (``set_telemetry``);
         with neither, the solve path is bit-identical to pre-telemetry
@@ -578,9 +582,13 @@ def run_pso_ga_batch(problems: Sequence[ProblemLike],
         for b in fleet.buckets:
             nb = int(b.idx.shape[0])
             name = f"{b.max_p}x{b.max_S}"
-            with maybe_span(tel, "swarm_init", bucket=name, n=nb):
-                keys_a, X0b, incb, migb = _init_bucket(
+            with maybe_span(tel, "swarm_init", bucket=name,
+                            n=nb) as init_args:
+                keys_a, X0b, incb, migb, n_groups = _init_bucket(
                     b, probs, seeds, cfg, incumbent, mig_arr, warm_rescue)
+                if tel is not None:
+                    init_args["groups"] = n_groups
+                    tel.inc("swarm_init.groups", n_groups)
             with maybe_span(tel, "fleet_inputs", bucket=name, n=nb):
                 arrb = None
                 if traffic:
@@ -637,30 +645,69 @@ def run_pso_ga_batch(problems: Sequence[ProblemLike],
             return results, _gather_state(bucket_states, n, cfg)
 
 
+#: traces of the grouped init program (python side effect: trace-time
+#: only, like ``_CACHE_STATS["traces"]``) — a repeat fleet, or one whose
+#: group sizes round to the same power of two, must not add one.
+_INIT_STATS = {"traces": 0}
+
+_KEY_WORD = 0xFFFFFFFF
+
+
+def _root_keys(seeds: Sequence[int]) -> np.ndarray:
+    """``(n, 2)`` uint32: ``jax.random.PRNGKey(seed)`` of every seed,
+    built on the host. Without x64, JAX keeps the seed's low 32 bits
+    (the high word is 0); with it, the seed's two's-complement words."""
+    x64 = bool(jax.config.jax_enable_x64)
+    return np.array([[(s >> 32) & _KEY_WORD if x64 else 0, s & _KEY_WORD]
+                     for s in seeds], np.uint32)
+
+
+@partial(jax.jit, static_argnames="cfg")
+def _init_group(roots, home, allowed, pinned, by_power, inc, has_inc,
+                rescue, cfg: PSOGAConfig):
+    """``(keys, X0)`` of a group of same-shape problems: each problem's
+    root key split as ``run_pso_ga`` splits it, and ``init_swarm``'s
+    core vmapped over the group."""
+    _INIT_STATS["traces"] += 1
+    def one(root, *args):
+        key, k_init = jax.random.split(root)
+        return key, _init_core(k_init, *args, cfg=cfg)
+    return jax.vmap(one)(roots, home, allowed, pinned, by_power, inc,
+                         has_inc, rescue)
+
+
 def _init_bucket(b: FleetBucket, probs: Sequence[SimProblem],
                  seeds: Sequence[int], cfg: PSOGAConfig,
                  incumbent: Optional[Sequence[np.ndarray]],
                  mig_arr: np.ndarray,
                  warm_rescue: Optional[Sequence[bool]]
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """One bucket's solver inputs ``(keys, X0b, incb, migb)``.
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray,
+                            int]:
+    """One bucket's solver inputs ``(keys, X0b, incb, migb)`` and the
+    number of init programs it ran.
 
     Per-problem init mirrors run_pso_ga exactly: split the problem's own
     key, draw the link-aware swarm at the TRUE (p, S) shape, then embed
     into the bucket's padded gene space (padded genes start — and stay —
     0). Seeds, incumbents and rescue flags all route by ORIGINAL index,
-    so bucket assignment never reshuffles a problem's inputs."""
+    so bucket assignment never reshuffles a problem's inputs.
+
+    The draws keep the true shape, so the members are grouped by their
+    exact ``(p, S)`` and each group runs ONE jitted vmap of
+    ``init_swarm``'s core (DESIGN.md §12). A group's count is padded to
+    a power of two with copies of its first member (dropped after), so
+    a round with another fleet mix or demotion pattern reuses the
+    compiled program; each group is read back once."""
     nb = int(b.idx.shape[0])
-    keys_l = []
     X0b = np.zeros((nb, cfg.pop_size, b.max_p), np.int32)
     incb = np.zeros((nb, b.max_p), np.int32)
     migb = np.zeros((nb,), np.float32)
+    has_inc = np.zeros((nb,), bool)
+    rescue = np.zeros((nb,), bool)
+    groups: Dict[Tuple[int, int], List[int]] = {}
     for j, i in enumerate(b.idx):
         pr = probs[i]
-        key, k_init = jax.random.split(jax.random.PRNGKey(seeds[i]))
-        keys_l.append(np.asarray(key))
-        inc_i = None
-        rescue_i = False
+        groups.setdefault((pr.num_layers, pr.num_servers), []).append(j)
         if incumbent is not None and incumbent[i] is not None:
             inc_i = np.asarray(incumbent[i], np.int32)
             if inc_i.shape != (pr.num_layers,):
@@ -669,16 +716,28 @@ def _init_bucket(b: FleetBucket, probs: Sequence[SimProblem],
                     f"expected ({pr.num_layers},)")
             incb[j, :pr.num_layers] = inc_i
             migb[j] = mig_arr[i]
-            rescue_i = bool(warm_rescue[i]) if warm_rescue is not None \
+            has_inc[j] = True
+            rescue[j] = bool(warm_rescue[i]) if warm_rescue is not None \
                 else False
         # else: a demoted problem (stale incumbent, DESIGN.md §11)
         # solves cold inside the warm fleet: zero migration weight
-        # multiplies the term away bit-exactly, and init_swarm gets
-        # no incumbent — identical to a cold solve of problem i.
-        X0b[j, :, :pr.num_layers] = np.asarray(
-            init_swarm(k_init, pr, cfg, incumbent=inc_i,
-                       rescue=rescue_i))
-    return np.stack(keys_l), X0b, incb, migb
+        # multiplies the term away bit-exactly, and its init takes the
+        # cold rows — identical to a cold solve of problem i.
+    keys_a = _root_keys([seeds[i] for i in b.idx])
+    outs = []
+    for (p, _), js in groups.items():
+        host = [np.stack(a) for a in
+                zip(*(_init_arrays(probs[b.idx[j]]) for j in js))]
+        args = [keys_a[js], *host, incb[js, :p], has_inc[js], rescue[js]]
+        pad = bucket_size(len(js), floor=1) - len(js)
+        if pad:
+            args = [_pad_rows(a, pad) for a in args]
+        outs.append(_init_group(*args, cfg=cfg))
+    for ((p, _), js), (keys, X0) in zip(groups.items(),
+                                         jax.device_get(outs)):
+        keys_a[js] = keys[:len(js)]
+        X0b[js, :, :p] = X0[:len(js)]
+    return keys_a, X0b, incb, migb, len(groups)
 
 
 def _gather_state(bucket_states: Sequence[Tuple[FleetBucket, _SwarmState]],

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -112,6 +112,84 @@ def _home_servers(prob: SimProblem) -> np.ndarray:
     return np.array([pin_per_app.get(int(a), 0) for a in app_np], np.int32)
 
 
+def _init_arrays(prob: SimProblem
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """The host half of ``init_swarm``: numpy ``(home (p,), allowed (p, S),
+    pinned (p,), by_power (S,))`` — each layer's home server, the servers
+    a gene may start on, the pins, and the servers by DESCENDING power
+    (the rescue anchors' order)."""
+    p = prob.num_layers
+    home = _home_servers(prob)
+    allowed = np.asarray(prob.link_ok)[home, :]      # (p, S), a copy
+    allowed[np.arange(p), home] = True
+    pinned = np.asarray(prob.pinned, np.int32)
+    by_power = np.argsort(-np.asarray(prob.power),
+                          kind="stable").astype(np.int32)
+    return home, allowed, pinned, by_power
+
+
+def _anchor_rows(X: jnp.ndarray, home: jnp.ndarray, start: int,
+                 n_anchor: int, singles: jnp.ndarray) -> jnp.ndarray:
+    """``X`` with row ``start`` set to ``home`` and row ``start+1+k`` to
+    the single-server placement ``singles[k]`` for ``k < n_anchor-1``."""
+    row = jnp.arange(X.shape[0])[:, None]                       # (P, 1)
+    k = row - (start + 1)
+    single = singles[jnp.clip(k, 0, singles.shape[0] - 1)]      # (P, 1)
+    X = jnp.where((k >= 0) & (k < n_anchor - 1), single, X)
+    return jnp.where(row == start, home[None, :], X)
+
+
+def _init_core(k_init: jax.Array, home: jnp.ndarray, allowed: jnp.ndarray,
+               pinned: jnp.ndarray, by_power: jnp.ndarray,
+               inc: jnp.ndarray, has_inc: jnp.ndarray, rescue: jnp.ndarray,
+               cfg: PSOGAConfig) -> jnp.ndarray:
+    """The traceable half of ``init_swarm``: ``(P, p)`` int32 genes.
+
+    Static in ``cfg`` and the shapes ``(p, S)`` of ``allowed``; the
+    incumbent ``inc (p,)`` and the flags ``has_inc`` / ``rescue`` are
+    traced, so one program serves cold, warm and rescue problems alike
+    (``batch`` vmaps it over a group of same-shape problems). Every
+    draw is made in every mode, from the same key splits and at the
+    same shapes as a solve of that mode alone, and the flags pick among
+    the finished swarms: picking never changes a drawn bit."""
+    P = cfg.pop_size
+    p, s = allowed.shape
+    logits = jnp.where(allowed, 0.0, -jnp.inf)                  # (p, S)
+    k1, k_warm = jax.random.split(k_init)
+    # categorical broadcasts logits over the requested sample shape: the
+    # gumbel draw is (P, p, S) either way, so this samples bit-identically
+    # to materializing a (P, p, S) logits tensor — without the copy.
+    X = jax.random.categorical(
+        k1, logits, axis=-1, shape=(P, p)).astype(jnp.int32)
+    cold = X
+    if cfg.bias_init_to_tiers:
+        # Warm-start anchors (standard metaheuristic practice; ≤ S+1 of the
+        # swarm): the all-home placement (the paper's loose-deadline
+        # limiting solution) and the S single-server placements. The
+        # remaining ~P−S−1 particles stay random — diversity is preserved
+        # and every anchor can be displaced by a fitter random particle.
+        cold = _anchor_rows(X, home, 0, min(s + 1, P - 1),
+                            jnp.arange(s, dtype=jnp.int32))
+    n_elite = max(1, min(cfg.warm_elite, P))
+    n_neigh = min(int(round(cfg.warm_fraction * P)), P - n_elite)
+    warm = X.at[:n_elite].set(inc[None, :])
+    if n_neigh > 0:
+        k_mask, k_val = jax.random.split(k_warm)
+        mut = jax.random.uniform(k_mask, (n_neigh, p)) < cfg.warm_mutation
+        vals = jax.random.categorical(
+            k_val, logits, axis=-1, shape=(n_neigh, p)).astype(jnp.int32)
+        warm = warm.at[n_elite:n_elite + n_neigh].set(
+            jnp.where(mut, vals, inc[None, :]))
+    tail = n_elite + n_neigh
+    if cfg.bias_init_to_tiers and tail < P:
+        warm = jnp.where(rescue, _anchor_rows(
+            warm, home, tail, min(s + 1, P - tail), by_power), warm)
+    return _clamp_pins(jnp.where(has_inc, warm, cold), pinned)
+
+
+_init_one = jax.jit(_init_core, static_argnames="cfg")
+
+
 def init_swarm(key: jax.Array, prob: SimProblem, cfg: PSOGAConfig,
                incumbent: Optional[np.ndarray] = None,
                rescue: bool = False) -> jnp.ndarray:
@@ -143,54 +221,11 @@ def init_swarm(key: jax.Array, prob: SimProblem, cfg: PSOGAConfig,
     the same key split either way, so passing ``incumbent=None`` is
     bit-identical to the pre-warm-start initialization.
     """
-    p, s = prob.num_layers, prob.num_servers
-    home = _home_servers(prob)
-    link_ok = np.asarray(prob.link_ok)
-    allowed = link_ok[home, :].copy()            # (p, S)
-    allowed[np.arange(p), home] = True
-    # never initialize onto a *foreign* end device (free but slowest and
-    # behind two WIFI hops); mutation may still propose them.
-    logits = jnp.where(jnp.asarray(allowed), 0.0, -jnp.inf)   # (p, S)
-    k1, k_warm = jax.random.split(key)
-    # categorical broadcasts logits over the requested sample shape: the
-    # gumbel draw is (P, p, S) either way, so this samples bit-identically
-    # to materializing a (P, p, S) logits tensor — without the copy.
-    X = jax.random.categorical(
-        k1, logits, axis=-1, shape=(cfg.pop_size, p)).astype(jnp.int32)
-    if incumbent is not None:
-        inc = jnp.asarray(incumbent, jnp.int32)
-        n_elite = max(1, min(cfg.warm_elite, cfg.pop_size))
-        n_neigh = min(int(round(cfg.warm_fraction * cfg.pop_size)),
-                      cfg.pop_size - n_elite)
-        X = X.at[:n_elite].set(inc[None, :])
-        if n_neigh > 0:
-            k_mask, k_val = jax.random.split(k_warm)
-            mut = jax.random.uniform(
-                k_mask, (n_neigh, p)) < cfg.warm_mutation
-            vals = jax.random.categorical(
-                k_val, logits, axis=-1, shape=(n_neigh, p)
-            ).astype(jnp.int32)
-            X = X.at[n_elite:n_elite + n_neigh].set(
-                jnp.where(mut, vals, inc[None, :]))
-        tail = n_elite + n_neigh
-        if rescue and cfg.bias_init_to_tiers and tail < cfg.pop_size:
-            n_anchor = min(s + 1, cfg.pop_size - tail)
-            X = X.at[tail].set(jnp.asarray(home))
-            by_power = np.argsort(-np.asarray(prob.power), kind="stable")
-            for k in range(n_anchor - 1):
-                X = X.at[tail + 1 + k].set(
-                    jnp.full((p,), int(by_power[k]), jnp.int32))
-    elif cfg.bias_init_to_tiers:
-        # Warm-start anchors (standard metaheuristic practice; ≤ S+1 of the
-        # swarm): the all-home placement (the paper's loose-deadline
-        # limiting solution) and the S single-server placements. The
-        # remaining ~P−S−1 particles stay random — diversity is preserved
-        # and every anchor can be displaced by a fitter random particle.
-        n_anchor = min(s + 1, cfg.pop_size - 1)
-        X = X.at[0].set(jnp.asarray(home))
-        for k in range(n_anchor - 1):
-            X = X.at[1 + k].set(jnp.full((p,), k, jnp.int32))
-    return _clamp_pins(X, jnp.asarray(prob.pinned))
+    has_inc = incumbent is not None
+    inc = (np.asarray(incumbent, np.int32) if has_inc
+           else np.zeros(prob.num_layers, np.int32))
+    return _init_one(key, *_init_arrays(prob), inc, has_inc,
+                     has_inc and rescue, cfg=cfg)
 
 
 def swarm_step(pp: PaddedProblem, state: _SwarmState,

@@ -198,3 +198,224 @@ def test_batch_seed_rejects_non_int(fleet3):
         run_pso_ga_batch(fleet3, FAST, seed=0.5)
     with pytest.raises(ValueError):
         run_pso_ga_batch(fleet3, FAST, seed=np.zeros((2, 2), np.int32))
+
+
+# ---------------------------------------------------------------------------
+# grouped swarm init == the unbatched init_swarm, problem by problem
+# ---------------------------------------------------------------------------
+
+INIT_CFG = PSOGAConfig(pop_size=40)
+# problems: three alexnets (one (p, S) group of 3 — not a power of two),
+# one vgg19 on the same environment, and the Fig. 2 DAG on the 6-server
+# sample environment
+INIT_SEEDS = [5, 2**31 + 5, -7919, 2**32 + 5, 11]
+
+
+@pytest.fixture(scope="module")
+def init_fleet():
+    env_p, env_s = paper_environment(), sample_environment()
+    dags = [(zoo.alexnet(pin_server=k, deadline=6.0), env_p)
+            for k in range(3)]
+    dags += [(zoo.vgg19(pin_server=1, deadline=40.0), env_p),
+             (fig2_dag(env_s), env_s)]
+    return [SimProblem.build(d, e) for d, e in dags]
+
+
+def _reference_init(b, probs, seeds, cfg, incumbent, mig, rescue):
+    """The per-problem loop the grouped init replaces, over the public
+    unbatched ``init_swarm``."""
+    import jax
+    from repro.core.pso_ga import init_swarm
+    nb = len(b.idx)
+    keys = np.zeros((nb, 2), np.uint32)
+    X0b = np.zeros((nb, cfg.pop_size, b.max_p), np.int32)
+    incb = np.zeros((nb, b.max_p), np.int32)
+    migb = np.zeros((nb,), np.float32)
+    for j, i in enumerate(b.idx):
+        p = probs[i].num_layers
+        key, k_init = jax.random.split(jax.random.PRNGKey(seeds[i]))
+        keys[j] = np.asarray(key)
+        inc = None if incumbent is None else incumbent[i]
+        if inc is not None:
+            incb[j, :p] = inc
+            migb[j] = mig[i]
+        X0b[j, :, :p] = np.asarray(init_swarm(
+            k_init, probs[i], cfg, incumbent=inc,
+            rescue=inc is not None and rescue is not None and rescue[i]))
+    return keys, X0b, incb, migb
+
+
+# (problem subset, bucketed?, incumbents?, rescue flags, demoted index)
+INIT_CASES = {
+    "cold": ([0, 1, 2, 3, 4], True, False, None, None),
+    "warm": ([0, 1, 2, 3, 4], True, True, None, None),
+    "warm_rescue": ([0, 1, 2, 3, 4], True, True,
+                    [True, False, True, False, True], None),
+    "warm_demoted": ([0, 1, 2, 3, 4], True, True,
+                     [True, False, True, False, True], 2),
+    "two_groups": ([0, 1, 3], False, True, [False, True, True], None),
+    "non_pow2": ([0, 1, 2], True, False, None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(INIT_CASES))
+def test_grouped_init_matches_init_swarm(case, init_fleet):
+    from repro.core.batch import _init_bucket, pack_fleet
+    sel, bucketed, warm, rescue, demoted = INIT_CASES[case]
+    probs = [init_fleet[k] for k in sel]
+    seeds = [INIT_SEEDS[k] for k in sel]
+    rng = np.random.default_rng(3)
+    incumbent = None
+    if warm:
+        incumbent = [rng.integers(0, pr.num_servers, pr.num_layers)
+                     .astype(np.int32) for pr in probs]
+        if demoted is not None:
+            incumbent[demoted] = None
+    mig = np.linspace(0.5, 1.5, len(probs)).astype(np.float32)
+    fleet = pack_fleet(probs, bucket=bucketed)
+    n_groups = []
+    for b in fleet.buckets:
+        *got, groups = _init_bucket(b, probs, seeds, INIT_CFG, incumbent,
+                                    mig, rescue)
+        n_groups.append(groups)
+        want = _reference_init(b, probs, seeds, INIT_CFG, incumbent, mig,
+                               rescue)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(g, w)
+    if case == "two_groups":
+        assert n_groups == [2]
+    if case == "non_pow2":
+        assert n_groups == [1] and len(fleet.buckets[0].idx) == 3
+
+
+def _eager_init_swarm(key, prob, cfg, incumbent=None, rescue=False):
+    """The eager ``init_swarm`` the traced core replaced: the same draws,
+    with one ``.at[].set`` per anchor row."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.pso_ga import _home_servers
+    p, s, P = prob.num_layers, prob.num_servers, cfg.pop_size
+    home = _home_servers(prob)
+    allowed = np.asarray(prob.link_ok)[home, :].copy()
+    allowed[np.arange(p), home] = True
+    logits = jnp.where(jnp.asarray(allowed), 0.0, -jnp.inf)
+    k1, k_warm = jax.random.split(key)
+    X = jax.random.categorical(k1, logits, axis=-1,
+                               shape=(P, p)).astype(jnp.int32)
+    if incumbent is not None:
+        inc = jnp.asarray(incumbent, jnp.int32)
+        n_elite = max(1, min(cfg.warm_elite, P))
+        n_neigh = min(int(round(cfg.warm_fraction * P)), P - n_elite)
+        X = X.at[:n_elite].set(inc[None, :])
+        if n_neigh > 0:
+            k_mask, k_val = jax.random.split(k_warm)
+            mut = jax.random.uniform(k_mask, (n_neigh, p)) \
+                < cfg.warm_mutation
+            vals = jax.random.categorical(
+                k_val, logits, axis=-1, shape=(n_neigh, p)).astype(jnp.int32)
+            X = X.at[n_elite:n_elite + n_neigh].set(
+                jnp.where(mut, vals, inc[None, :]))
+        tail = n_elite + n_neigh
+        if rescue and cfg.bias_init_to_tiers and tail < P:
+            X = X.at[tail].set(jnp.asarray(home))
+            by_power = np.argsort(-np.asarray(prob.power), kind="stable")
+            for k in range(min(s + 1, P - tail) - 1):
+                X = X.at[tail + 1 + k].set(
+                    jnp.full((p,), int(by_power[k]), jnp.int32))
+    elif cfg.bias_init_to_tiers:
+        X = X.at[0].set(jnp.asarray(home))
+        for k in range(min(s + 1, P - 1) - 1):
+            X = X.at[1 + k].set(jnp.full((p,), k, jnp.int32))
+    pinned = jnp.asarray(prob.pinned)
+    return jnp.where(pinned[None, :] >= 0, pinned[None, :], X)
+
+
+# (cfg, incumbent?, rescue?): the population spans fewer and more rows
+# than the anchors need, so both anchor blocks get cut short somewhere
+CORE_CASES = {
+    "cold": (INIT_CFG, False, False),
+    "cold_short": (PSOGAConfig(pop_size=8), False, False),
+    "cold_unbiased": (PSOGAConfig(pop_size=40, bias_init_to_tiers=False),
+                      False, False),
+    "warm": (INIT_CFG, True, False),
+    "rescue": (INIT_CFG, True, True),
+    "rescue_short": (PSOGAConfig(pop_size=30), True, True),
+    "rescue_no_tail": (PSOGAConfig(pop_size=10, warm_fraction=0.9),
+                       True, True),
+}
+
+
+@pytest.mark.parametrize("case", list(CORE_CASES))
+def test_init_swarm_matches_eager_anchor_loops(case, init_fleet):
+    """``init_swarm`` (the traced core, anchors by row index) equals the
+    eager per-anchor loop it replaced, bit for bit."""
+    import jax
+    from repro.core.pso_ga import init_swarm
+    cfg, warm, rescue = CORE_CASES[case]
+    rng = np.random.default_rng(5)
+    for k, prob in enumerate(init_fleet):
+        key = jax.random.PRNGKey(INIT_SEEDS[k])
+        inc = rng.integers(0, prob.num_servers, prob.num_layers) \
+            if warm else None
+        got = np.asarray(init_swarm(key, prob, cfg, incumbent=inc,
+                                    rescue=rescue))
+        want = np.asarray(_eager_init_swarm(key, prob, cfg, incumbent=inc,
+                                            rescue=rescue))
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("seed", [0, 1, 2**31 - 1, 2**31, 2**32 + 5, -1,
+                                  -7919])
+def test_root_keys_match_prngkey(seed, x64):
+    """Host-built key words equal ``jax.random.PRNGKey(seed)``: without
+    x64 JAX keeps only the seed's low 32 bits, so a naive
+    ``[seed >> 32, seed & mask]`` is wrong for the last three seeds."""
+    import jax
+    from repro.core.batch import _root_keys
+    with jax.enable_x64(x64):
+        want = np.asarray(jax.random.PRNGKey(seed))
+        got = _root_keys([seed])[0]
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+
+
+def test_grouped_init_not_retraced(init_fleet):
+    """The init program traces once per (cfg, group shape, padded
+    count): a repeat fleet, a warm round of it, and a fleet whose group
+    counts round to the same power of two all reuse it."""
+    from repro.core.batch import _INIT_STATS, _init_bucket, pack_fleet
+    cfg = PSOGAConfig(pop_size=40, warm_elite=3)   # a cfg of its own
+
+    def init(sel, warm=False):
+        probs = [init_fleet[k] for k in sel]
+        inc = [np.zeros(pr.num_layers, np.int32) for pr in probs] \
+            if warm else None
+        b, = pack_fleet(probs).buckets
+        _init_bucket(b, probs, list(range(len(probs))), cfg, inc,
+                     np.ones(len(probs), np.float32), None)
+
+    before = _INIT_STATS["traces"]
+    init([0, 1, 2])                            # 3 alexnets, padded to 4
+    traced = _INIT_STATS["traces"]
+    assert traced == before + 1
+    init([0, 1, 2])
+    init([0, 1, 2], warm=True)
+    init([0, 1, 2, 0])                         # 4 rounds to 4 as well
+    assert _INIT_STATS["traces"] == traced
+    init([0, 1])                               # 2: a new padded count
+    assert _INIT_STATS["traces"] == traced + 1
+
+
+def test_swarm_init_groups_telemetry(init_fleet):
+    """``swarm_init`` spans carry their bucket's group count and the
+    ``swarm_init.groups`` counter sums them."""
+    from repro.core.telemetry import Telemetry
+    tel = Telemetry()
+    probs = [init_fleet[k] for k in (0, 1, 3)]
+    run_pso_ga_batch(probs, FAST, seed=0, bucket=False, telemetry=tel)
+    spans = [e for e in tel.tracer.events()
+             if e["ph"] == "B" and e["name"] == "swarm_init"]
+    assert [s["args"]["groups"] for s in spans] == [2]
+    assert tel.registry.snapshot()["counters"]["swarm_init.groups"] == 2
